@@ -25,6 +25,7 @@ sys.path.insert(0, os.path.join(
 from repro.analysis import analyze, analyze_cached  # noqa: E402
 from repro.analysis.lint import _default_config, suite  # noqa: E402
 from repro.fleet.scheduler import check_job  # noqa: E402
+from repro.fleet import enable_compile_cache  # noqa: E402
 
 
 def _time_paired(fn_a, fn_b, reps: int, rounds: int = 9):
@@ -49,6 +50,7 @@ def main(argv=None) -> int:
                    help="fewer reps (CI gate)")
     p.add_argument("--json", action="store_true")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     cfg = _default_config()
     benches = suite(cfg)

@@ -37,6 +37,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
 
+from repro.fleet import enable_compile_cache  # noqa: E402
+
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -121,6 +123,7 @@ def main() -> None:
     ap.add_argument("--json", default=os.path.join(
         _REPO_ROOT, "BENCH_tier_policy.json"))
     args = ap.parse_args()
+    enable_compile_cache()
 
     doc = calibrate(smoke=args.smoke, repeats=args.repeats)
     print(f"backend={doc['backend']} "

@@ -37,6 +37,7 @@ import numpy as np  # noqa: E402
 from benchmarks.fleet import build_jobs, fleet_config  # noqa: E402
 from repro.core import compile_program, run_compiled, run_program  # noqa: E402
 from repro.obs import Tracer  # noqa: E402
+from repro.fleet import enable_compile_cache  # noqa: E402
 from repro.programs import (build_bitonic, build_fft, build_matmul,  # noqa: E402
                             build_reduction, build_transpose)
 
@@ -189,6 +190,7 @@ def main() -> None:
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="record a repro.obs trace of the whole run")
     args = ap.parse_args()
+    enable_compile_cache()
 
     tracer = Tracer("bench-compiled") if args.trace else None
     with (tracer if tracer is not None else contextlib.nullcontext()):

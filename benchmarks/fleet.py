@@ -28,7 +28,8 @@ sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
 
 from repro.core import EGPUConfig, run_program  # noqa: E402
-from repro.fleet import Fleet, FaultPlan, FleetService  # noqa: E402
+from repro.fleet import (Fleet, FaultPlan, FleetService,  # noqa: E402
+                         enable_compile_cache)
 from repro.obs import Tracer  # noqa: E402
 from repro.programs import (build_bitonic, build_fft, build_matmul,  # noqa: E402
                             build_reduction, build_transpose)
@@ -560,6 +561,7 @@ def main() -> None:
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="record a repro.obs trace of the whole run")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.serve_smoke:
         serve_smoke()

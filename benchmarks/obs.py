@@ -39,7 +39,7 @@ sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
 
 from benchmarks.fleet import build_jobs, fleet_config  # noqa: E402
-from repro.fleet import Fleet, FleetService  # noqa: E402
+from repro.fleet import Fleet, FleetService, enable_compile_cache  # noqa: E402
 from repro.obs import Tracer, aggregate  # noqa: E402
 from repro.obs.report import build_tree, coverage  # noqa: E402
 
@@ -188,6 +188,7 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="write the traced drain's Perfetto JSON here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         args.rounds, args.repeats, args.mix = 1, 3, "light"

@@ -40,6 +40,7 @@ from benchmarks.fleet import fleet_config  # noqa: E402
 from benchmarks.superblock import _loop_nested, _loop_saxpy  # noqa: E402
 from repro.core import compile_program, run_program  # noqa: E402
 from repro.core.blockc import BlockCompileError  # noqa: E402
+from repro.fleet import enable_compile_cache  # noqa: E402
 from repro.programs import (build_bitonic, build_fft, build_matmul,  # noqa: E402
                             build_reduction, build_transpose)
 
@@ -154,6 +155,7 @@ def main() -> None:
     ap.add_argument("--json", default=os.path.join(_REPO_ROOT,
                                                    "BENCH_compiled.json"))
     args = ap.parse_args()
+    enable_compile_cache()
 
     out = bench(args.smoke, args.repeats)
 

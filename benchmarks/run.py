@@ -24,6 +24,7 @@ sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
 
 from benchmarks import paper_tables  # noqa: E402
+from repro.fleet import enable_compile_cache  # noqa: E402
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _ROWS: list[dict] = []
@@ -51,6 +52,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="smallest sizes / fewest rounds, for CI")
     args = ap.parse_args()
+    enable_compile_cache()
     global _PERSIST
     _PERSIST = not args.smoke
 

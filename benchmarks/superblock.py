@@ -50,7 +50,7 @@ from benchmarks.fleet import fleet_config  # noqa: E402
 from repro.core import Asm, compile_program, run_program  # noqa: E402
 from repro.core.blockc import (DEFAULT_TIER_POLICY, _sched_insts,  # noqa: E402
                                _trace_cost)
-from repro.fleet import Fleet  # noqa: E402
+from repro.fleet import Fleet, enable_compile_cache  # noqa: E402
 from repro.obs import Tracer  # noqa: E402
 from repro.programs import build_matmul, build_transpose  # noqa: E402
 
@@ -393,6 +393,7 @@ def main() -> None:
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="record a repro.obs trace of the whole run")
     args = ap.parse_args()
+    enable_compile_cache()
 
     tracer = Tracer("bench-superblock") if args.trace else None
     with (tracer if tracer is not None else contextlib.nullcontext()):

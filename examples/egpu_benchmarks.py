@@ -4,8 +4,11 @@ ablation end to end, printing the comparison against the paper.
   PYTHONPATH=src python examples/egpu_benchmarks.py
 """
 from repro.core import benchmark_config
+from repro.fleet import enable_compile_cache
 from repro.programs import (build_bitonic, build_fft, build_matmul,
                             build_reduction, build_transpose, run_bench)
+
+enable_compile_cache()
 
 PAPER = {"reduction": 202, "transpose": 5529, "matmul": 26278,
          "bitonic": 3728, "fft": 1695}

@@ -19,11 +19,12 @@ import numpy as np
 
 from repro.core import machine as machine_mod
 from repro.core import run_program
-from repro.fleet import Fleet
+from repro.fleet import Fleet, enable_compile_cache
 from benchmarks.fleet import build_jobs, fleet_config
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = fleet_config()
     jobs = build_jobs(cfg, 96, mix="suite")
     print(f"{len(jobs)} jobs over {len({b.name for b in jobs})} distinct "

@@ -5,6 +5,9 @@
 import numpy as np
 
 from repro.core import Asm, benchmark_config, machine, profile, run_program
+from repro.fleet import enable_compile_cache
+
+enable_compile_cache()
 
 # 1. Configure an eGPU instance (static scalability: every knob is a
 #    configuration-time parameter, paper Tables 4-6).

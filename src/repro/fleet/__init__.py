@@ -26,7 +26,8 @@ with backoff, bounded admission, deterministic fault injection):
         fut.result()                     # JobResult, or raises JobError
 """
 from .api import Fleet, run_jobs, serve_jobs
-from .devices import balance_units, device_label, fleet_devices, make_job_mesh
+from .devices import (balance_units, device_label, enable_compile_cache,
+                      fleet_devices, make_job_mesh)
 from .engine import ResidencyCache, fleet_run, stack_states, unstack_state
 from .faults import FAULT_SITES, FaultPlan, FaultSpec, InjectedFault
 from .scheduler import (FleetJob, FleetScheduler, FleetStats, JobResult,
@@ -40,7 +41,7 @@ __all__ = [
     "unstack_state", "FleetJob", "FleetScheduler", "FleetStats",
     "JobResult", "ResidencyCache", "check_job",
     "ShardedFleetScheduler", "fleet_devices", "device_label",
-    "make_job_mesh", "balance_units",
+    "make_job_mesh", "balance_units", "enable_compile_cache",
     "FleetService", "ServiceStats", "JobError", "AdmissionError",
     "register_serve_metrics",
     "FaultPlan", "FaultSpec", "InjectedFault", "FAULT_SITES",

@@ -21,15 +21,40 @@ module owns the three primitives everything above builds on:
 Everything here is topology-only: no dispatch, no state.  The sharded
 scheduler (``fleet/sharded.py``) and the serving layer
 (``fleet/service.py``) compose these with per-device
-``FleetScheduler`` instances.
+``FleetScheduler`` instances.  ``enable_compile_cache()`` is the one
+process-wide setting: entry points call it before their first compile.
 """
 from __future__ import annotations
 
+import os
+from pathlib import Path
 from typing import Any, Callable, Sequence
 
 import jax
 
 DeviceSpec = Any  # None | int | "all" | Device | Sequence[Device]
+
+#: ``.jax_cache/`` at the root of the checkout (``src/repro/fleet/`` is
+#: three levels below it)
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` names the directory when it is set;
+    otherwise it is :data:`DEFAULT_COMPILE_CACHE`, a fixed path, so a
+    rerun from the same checkout finds what the last run compiled.
+    Every compile is kept, however short: the fleet's AOT light-path
+    and runner compiles are mostly sub-second each, but a cold drain
+    pays for dozens of them.  Entry points call this before their first
+    compile; importing a module never does.
+    """
+    path = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or str(DEFAULT_COMPILE_CACHE))
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 def device_label(dev) -> str:

@@ -65,10 +65,21 @@ from .engine import ResidencyCache
 from .scheduler import (DrainCancelled, FleetJob, FleetScheduler,
                         JobResult, _prog_digest, _result_checksum)
 
-__all__ = ["ShardedFleetScheduler"]
+__all__ = ["ShardedFleetScheduler", "mega_light_fn"]
 
 #: AOT shard_map executables kept per scheduler (LRU)
 _MEGA_EXECS_MAX = 32
+
+
+def mega_light_fn(cp, mesh):
+    """``cp``'s light path as one ``shard_map`` over the 1-D job mesh:
+    ``(shared (N, S), tdx (N,)) -> (shared, cycles, halted)`` with the
+    leading (job) axis split across the mesh's devices.  Every row is an
+    independent core, so this is bit-identical to the single-device
+    call."""
+    return jax.shard_map(cp.light_fn(), mesh=mesh,
+                         in_specs=(P("jobs", None), P("jobs")),
+                         out_specs=(P("jobs", None), P("jobs"), P("jobs")))
 
 
 class ShardedFleetScheduler(FleetScheduler):
@@ -129,8 +140,6 @@ class ShardedFleetScheduler(FleetScheduler):
     def _mega_exec(self, cp, shared, tdx):
         """The AOT-compiled ``shard_map`` light executable for this
         (program, slab shape), plus compile seconds (0.0 when warm)."""
-        from jax.experimental.shard_map import shard_map
-
         key = (program_key(cp.image), cp.threads, cp.mode,
                np.shape(shared))
         e = self._mega_execs.get(key)
@@ -143,11 +152,8 @@ class ShardedFleetScheduler(FleetScheduler):
         with obs_trace.span("compile", kind="xla_mega", tier=cp.mode,
                             batch=np.shape(shared)[0],
                             devices=self.n_devices):
-            fn = shard_map(cp.light_fn(), mesh=self._mesh,
-                           in_specs=(P("jobs", None), P("jobs")),
-                           out_specs=(P("jobs", None), P("jobs"),
-                                      P("jobs")))
-            exe = jax.jit(fn).lower(shared, tdx).compile()
+            exe = jax.jit(mega_light_fn(cp, self._mesh)).lower(
+                shared, tdx).compile()
         self._mega_execs[key] = {"cp": cp, "exe": exe}
         self._mega_execs.move_to_end(key)
         while len(self._mega_execs) > _MEGA_EXECS_MAX:

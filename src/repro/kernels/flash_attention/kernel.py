@@ -19,8 +19,6 @@ from jax import numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import tpu_compiler_params as _CompilerParams
-
 DEFAULT_TILE_Q = 128
 DEFAULT_TILE_K = 128
 NEG_INF = -1e30
@@ -123,7 +121,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), qr, kr, vr)

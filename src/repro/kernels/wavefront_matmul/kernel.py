@@ -20,8 +20,6 @@ from jax import numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import tpu_compiler_params as _CompilerParams
-
 TILE_M = 128
 TILE_N = 128
 TILE_K = 128
@@ -73,7 +71,7 @@ def wavefront_matmul(a: jnp.ndarray, b: jnp.ndarray,
             scratch_shapes=[pltpu.VMEM((TILE_M, TILE_N), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(row_active.astype(jnp.int32), a, b)

@@ -89,6 +89,13 @@ def test_fft(n):
     assert abs(r.cycles - p) / p < 0.35
 
 
+def test_fft_16_runs_one_wavefront_for_eight_butterflies():
+    """n = 16 needs 8 butterflies but a whole 16-thread wavefront runs;
+    the extra threads must not corrupt the result (``_run`` checks it
+    against the NumPy oracle)."""
+    _run(build_fft, 16)
+
+
 def test_fft_qp_ratio_matches_paper():
     dp = _run(build_fft, 64)
     qp = _run(build_fft, 64, "qp")
