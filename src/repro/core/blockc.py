@@ -79,7 +79,6 @@ entirely.
 """
 from __future__ import annotations
 
-import functools
 import hashlib
 import time
 from typing import Any, NamedTuple
@@ -97,7 +96,7 @@ from .config import EGPUConfig
 from .executor import (_PF_IMM, _PF_OP, _PF_RA, _PF_RB, _PF_RD, _PF_TSC,
                        _PF_TYP, _TC_CLS, _TC_LAT, _TC_PER_WF0, _TC_READS_RA,
                        _TC_READS_RB, _TC_READS_RD, _TC_SCALAR, _TC_WRITES_PRED,
-                       _TC_WRITES_RD, pad_image, tables_np)
+                       _TC_WRITES_RD, name_kernel, pad_image, tables_np)
 from .isa import Op, Typ
 from .machine import MachineState
 from ..obs import trace as obs_trace
@@ -1113,7 +1112,6 @@ class CompiledProgram:
         stat_c = sim.stat_cycles if self.validate else zeros
         stat_i = sim.stat_instrs if self.validate else zeros
 
-        @functools.partial(jax.jit, donate_argnums=(0,))
         def run(shared, tdx_dim):
             batch = shared.shape[:-1]          # () or (B,)
             regs, shared_f, pstack, pdepth = self._super_final(
@@ -1138,7 +1136,11 @@ class CompiledProgram:
                 hazard=b(jnp.asarray(sim.hazard)),
                 hazard_violations=b(jnp.int32(sim.violations)))
 
-        return run
+        return jax.jit(self._named(run, "superblock"), donate_argnums=(0,))
+
+    def _named(self, fn, tier: str):
+        """``fn`` named as this program's ``tier`` kernel."""
+        return name_kernel(fn, tier, program_digest(self.image))
 
     # ------------------------------------------------------------- driver
     def _blocks_final(self, shared, tdx_dim):
@@ -1187,7 +1189,6 @@ class CompiledProgram:
         # the fresh sequencer state are constants inside the jit, and the
         # final MachineState (including the statically baked hazard rows)
         # is assembled inside it too.  The shared-memory image is donated.
-        @functools.partial(jax.jit, donate_argnums=(0,))
         def run(shared, tdx_dim):
             batch = shared.shape[:-1]          # () or (B,)
             d, s = self._blocks_final(shared, tdx_dim)
@@ -1207,7 +1208,7 @@ class CompiledProgram:
                 hazard=b(jnp.asarray(hazard)),
                 hazard_violations=b(jnp.int32(violations)))
 
-        return run
+        return jax.jit(self._named(run, "blocks"), donate_argnums=(0,))
 
     def light_fn(self):
         """The *unjitted* light-path function ``(shared, tdx_dim) ->
@@ -1225,7 +1226,7 @@ class CompiledProgram:
                 return (shared_f,
                         jnp.broadcast_to(jnp.int32(sim.cycles), batch),
                         jnp.broadcast_to(jnp.bool_(sim.halted), batch))
-            return run
+            return self._named(run, self.mode)
 
         def run(shared, tdx_dim):
             batch = shared.shape[:-1]
@@ -1233,7 +1234,7 @@ class CompiledProgram:
             return (d.shared,
                     jnp.broadcast_to(s.cycles, batch),
                     jnp.broadcast_to(s.halted, batch))
-        return run
+        return self._named(run, self.mode)
 
     def _build_light_runner(self):
         """The light path: only ``(shared, cycles, halted)`` leave the
@@ -1374,6 +1375,13 @@ def program_key(image: ProgramImage) -> bytes:
     return image.words.tobytes()
 
 
+def program_digest(image: ProgramImage) -> str:
+    """Short content digest of a program: the fleet's ``program``
+    metric label and the suffix of its kernels' XLA module names
+    (bounded cardinality: one value per distinct program)."""
+    return hashlib.blake2b(program_key(image), digest_size=4).hexdigest()
+
+
 def normalize_threads(image: ProgramImage, threads: int | None) -> int:
     """``None`` means "the count the image was assembled for"; anything
     else must be an explicit valid count.  In particular ``threads=0``
@@ -1442,8 +1450,7 @@ def compile_program(image: ProgramImage, threads: int | None = None, *,
             except BlockCompileError as e:
                 hit = e                  # negative-cache the rejection
         if sp.active:
-            sp.set(program=hashlib.blake2b(
-                       key[1], digest_size=4).hexdigest(),
+            sp.set(program=program_digest(image),
                    tier=getattr(hit, "mode", "rejected"))
     _CACHE[key] = hit
     if isinstance(hit, BlockCompileError):

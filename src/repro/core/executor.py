@@ -25,6 +25,7 @@ apply every architectural update exactly once with mask-gated selects.
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +45,22 @@ _U32 = jnp.uint32
 # virtual hazard slots
 _HZ_MEM = -2
 _HZ_PRED = -1
+
+#: the XLA module name of every tier kernel: ``jit_egpu_<tier>``, with
+#: ``_<program digest>`` where the kernel runs one program
+KERNEL_MODULE_RE = re.compile(
+    r"jit_egpu_(superblock|blocks|interp|mega_superblock|mega_blocks)"
+    r"(?:_([0-9a-f]{8}))?")
+
+
+def name_kernel(fn, tier: str, program: str | None = None):
+    """Name ``fn`` so that ``jax.jit(fn)`` lowers to the XLA module
+    ``jit_egpu_<tier>[_<program>]`` (see :data:`KERNEL_MODULE_RE`): a
+    stable name per tier kernel in HLO dumps and profiler traces.
+    ``program`` is the program's digest.  Returns ``fn``."""
+    name = f"egpu_{tier}" if program is None else f"egpu_{tier}_{program}"
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 # ---------------------------------------------------------------------------
@@ -374,15 +391,14 @@ def _make_runner(cfg: EGPUConfig, prog_len: int,
     def cond(carry):
         return running(carry[0])
 
-    # the carried machine state is donated: XLA reuses its buffers
-    # in-place instead of copying the register file / shared memory on
-    # every dispatch (callers get a fresh state back)
-    @functools.partial(jax.jit, donate_argnums=(1,))
     def run(prog, st):
         final, _ = lax.while_loop(cond, body, (st, prog))
         return final
 
-    return run
+    # the carried machine state is donated: XLA reuses its buffers
+    # in-place instead of copying the register file / shared memory on
+    # every dispatch (callers get a fresh state back)
+    return jax.jit(name_kernel(run, "interp"), donate_argnums=(1,))
 
 
 def padded_length(n: int) -> int:

@@ -37,7 +37,8 @@ from jax import lax
 from ..core import semantics
 from ..core.assembler import ProgramImage
 from ..core.config import EGPUConfig
-from ..core.executor import make_step, pad_image, padded_length
+from ..core.executor import (make_step, name_kernel, pad_image,
+                             padded_length)
 from ..core.isa import Op
 from ..core.machine import MachineState, init_state
 from ..obs import metrics as obs_metrics
@@ -156,15 +157,14 @@ def _make_fleet_runner(cfg: EGPUConfig, prog_len: int,
             states = substep(states, progs)
         return (states, progs)
 
-    # donate the carried batch state: XLA reuses the (N, T, R) register
-    # files / (N, S) shared memories in place instead of copying them on
-    # every dispatch (callers get the final state back)
-    @functools.partial(jax.jit, donate_argnums=(1,))
     def run(progs, states):
         final, _ = lax.while_loop(cond, body, (states, progs))
         return final
 
-    return run
+    # donate the carried batch state: XLA reuses the (N, T, R) register
+    # files / (N, S) shared memories in place instead of copying them on
+    # every dispatch (callers get the final state back)
+    return jax.jit(name_kernel(run, "interp"), donate_argnums=(1,))
 
 
 def _pack_programs(images: list[ProgramImage], prog_len: int | None = None):

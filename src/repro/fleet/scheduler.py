@@ -60,7 +60,7 @@ from ..core import machine as machine_mod
 from ..core.assembler import Asm, ProgramImage
 from ..core.blockc import (BlockCompileError, TierPolicy, compile_program,
                            default_policy_for_device, normalize_threads,
-                           program_key)
+                           program_digest, program_key)
 from ..core.config import EGPUConfig
 from ..core.executor import padded_length
 from ..core.machine import MachineState
@@ -152,13 +152,6 @@ class DrainCancelled(RuntimeError):
     drain this way so the orphaned thread stops at the next unit
     boundary instead of grinding through (and cold-compiling for) the
     rest of the queue nobody will read."""
-
-
-def _prog_digest(image: ProgramImage) -> str:
-    """Short content digest of a program — the ``program`` metric
-    label (bounded cardinality: one value per distinct program)."""
-    return hashlib.blake2b(program_key(image),
-                           digest_size=4).hexdigest()
 
 
 def _result_checksum(res: "JobResult") -> bytes:
@@ -395,6 +388,9 @@ def register_fleet_metrics(reg: obs_metrics.MetricsRegistry) -> None:
                 "batch execution wall time (compile excluded)")
     reg.counter("fleet_compile_seconds_total",
                 "host + XLA compile seconds")
+    reg.counter("fleet_collect_seconds_total",
+                "host seconds collecting batch results (device-to-host "
+                "copy, per-job results), after the batch wall time")
     reg.counter("fleet_residency_lookups_total",
                 "device-resident input lookups", ("result",))
     reg.counter("fleet_compile_cache_total",
@@ -661,9 +657,6 @@ class FleetScheduler:
             if cp is None:
                 rest.extend(group)
                 continue
-            self._event("tier_group", program=_prog_digest(cp.image),
-                        jobs=len(group), threads=cp.threads,
-                        batch_hint=hint, tier=cp.mode)
             compiled.append((cp, group))
         return compiled, rest
 
@@ -718,6 +711,7 @@ class FleetScheduler:
                  results: dict[int, JobResult]) -> None:
         """Slice per-job results out of a batched final state (one host
         transfer per leaf, then pure-NumPy scatter to jobs)."""
+        t0 = time.perf_counter()
         shared = np.asarray(final.shared)
         cycles = np.asarray(final.cycles)
         steps = np.asarray(final.steps)
@@ -751,6 +745,7 @@ class FleetScheduler:
         m.inc("fleet_wall_seconds_total", wall)
         m.inc("fleet_cycles_total", sum_cycles)
         m.inc("fleet_steps_total", sum_steps)
+        m.inc("fleet_collect_seconds_total", time.perf_counter() - t0)
 
     def _job_counters(self, job: FleetJob) -> EventCounters | None:
         """Event counters for an interpreter-tier job (tracing only):
@@ -834,6 +829,7 @@ class FleetScheduler:
         from the compile-time path simulation — identical for every
         lock-step core running the program, and bit-identical to what
         ``run()`` returns (the equivalence suites pin this)."""
+        t0 = time.perf_counter()
         shared = np.asarray(shared_dev)
         sim = cp.sim
         zeros = np.zeros((isa.NUM_OP_CLASSES,), np.int32)
@@ -855,7 +851,7 @@ class FleetScheduler:
                 tr.async_end("job", id=job.handle, cycles=cycles,
                              tier=cp.mode)
         # one registry pass per batch, not per job (hot path)
-        prog = _prog_digest(cp.image)
+        prog = program_digest(cp.image)
         m = self._m
         m.inc("fleet_batches_total", tier=cp.mode, program=prog,
               device=self._dev)
@@ -865,6 +861,7 @@ class FleetScheduler:
         m.inc("fleet_wall_seconds_total", wall)
         m.inc("fleet_cycles_total", cycles * real)
         m.inc("fleet_steps_total", steps * real)
+        m.inc("fleet_collect_seconds_total", time.perf_counter() - t0)
 
     def _run_compiled_unit(self, cp, chunk: list[FleetJob],
                            results: dict[int, JobResult]) -> None:
@@ -872,11 +869,10 @@ class FleetScheduler:
         run through the light path over device-resident inputs."""
         real = len(chunk)
         with obs_trace.span("batch", tier=cp.mode, jobs=real):
-            with obs_trace.span("bucket"):
-                size = self.batch_size if self.fixed_bucket else \
-                    self._bucket(real, self.batch_size)
-                pad = size - real
-                chunk = chunk + chunk[:1] * pad   # same-program filler
+            size = self.batch_size if self.fixed_bucket else \
+                self._bucket(real, self.batch_size)
+            pad = size - real
+            chunk = chunk + chunk[:1] * pad   # same-program filler
             t0 = time.perf_counter()
             with obs_trace.span("residency") as rsp:
                 (shared_dev, tdx_dev), res_hit = \

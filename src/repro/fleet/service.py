@@ -169,6 +169,9 @@ def register_serve_metrics(reg: obs_metrics.MetricsRegistry,
                 "cohorts handed to a scheduler, by device", ("device",))
     reg.counter("serve_dispatched_jobs_total",
                 "jobs across all dispatched cohorts")
+    reg.counter("serve_queue_wait_seconds_total",
+                "seconds dispatched jobs waited in the queue, from their "
+                "(re-)enqueue to their cohort's dispatch")
     reg.counter("serve_scheduler_resets_total",
                 "schedulers abandoned (hang/crash)",
                 ("reason", "device"))
@@ -565,45 +568,18 @@ class FleetService:
                 stack.enter_context(self.recorder.installed())
             stack.enter_context(self.metrics.installed())
             while True:
-                expired, cohort = [], []
                 with self._work:
                     if idx in self._dead:
                         break            # retired: survivors take over
                     if self._closed and not self._queue:
                         break
                     now = time.monotonic()
-                    expired = [t for t in self._queue
-                               if t.deadline is not None
-                               and now >= t.deadline]
-                    if expired:
-                        gone = {t.tid for t in expired}
-                        self._queue = [t for t in self._queue
-                                       if t.tid not in gone]
-                        for t in expired:
-                            self._pending_cost -= t.cost
-                            self._inflight_cost += t.cost  # _fail releases
-                        self._work.notify_all()
-                    else:
-                        ready = [t for t in self._queue
-                                 if t.not_before <= now]
-                        oldest = min((t.enqueue_t for t in ready),
-                                     default=None)
-                        full = len(ready) >= self.batch_size
-                        due = oldest is not None \
-                            and now - oldest >= self.max_delay_s
-                        if ready and (full or due or self._closed):
-                            ready.sort(key=lambda t: (t.priority, t.tid))
-                            cohort = ready[:self.batch_size]
-                            gone = {t.tid for t in cohort}
-                            self._queue = [t for t in self._queue
-                                           if t.tid not in gone]
-                            for t in cohort:
-                                self._pending_cost -= t.cost
-                                self._inflight_cost += t.cost
-                            self._update_gauges()
-                        else:
+                    with obs_trace.span("serve.cohort"):
+                        expired, cohort = self._take(now)
+                    if not (expired or cohort):
+                        with obs_trace.span("serve.wait"):
                             self._work.wait(self._next_wake(now))
-                            continue
+                        continue
                 # futures resolve outside the lock (their callbacks may
                 # re-enter submit)
                 for t in expired:
@@ -611,6 +587,37 @@ class FleetService:
                                detail="deadline passed before dispatch")
                 if cohort:
                     self._dispatch(cohort, idx)
+
+    def _take(self, now: float) -> tuple[list[_Ticket], list[_Ticket]]:
+        """Take the expired jobs off the queue, or else the next cohort
+        if its batching trigger (full, due, or closing) has fired;
+        returns ``(expired, cohort)``, both empty when there is nothing
+        to do yet.  Caller holds the lock."""
+        expired = [t for t in self._queue
+                   if t.deadline is not None and now >= t.deadline]
+        if expired:
+            gone = {t.tid for t in expired}
+            self._queue = [t for t in self._queue if t.tid not in gone]
+            for t in expired:
+                self._pending_cost -= t.cost
+                self._inflight_cost += t.cost  # _fail releases
+            self._work.notify_all()
+            return expired, []
+        ready = [t for t in self._queue if t.not_before <= now]
+        oldest = min((t.enqueue_t for t in ready), default=None)
+        full = len(ready) >= self.batch_size
+        due = oldest is not None and now - oldest >= self.max_delay_s
+        if not (ready and (full or due or self._closed)):
+            return [], []
+        ready.sort(key=lambda t: (t.priority, t.tid))
+        cohort = ready[:self.batch_size]
+        gone = {t.tid for t in cohort}
+        self._queue = [t for t in self._queue if t.tid not in gone]
+        for t in cohort:
+            self._pending_cost -= t.cost
+            self._inflight_cost += t.cost
+        self._update_gauges()
+        return [], cohort
 
     def _next_wake(self, now: float) -> float | None:
         """Seconds until the next scheduled trigger (batch-delay expiry,
@@ -645,6 +652,8 @@ class FleetService:
         m.inc("serve_dispatches_total", device=label)
         m.inc("serve_dispatched_jobs_total", len(cohort))
         now = time.monotonic()
+        m.inc("serve_queue_wait_seconds_total",
+              sum(now - t.enqueue_t for t in cohort))
         if self._tm:
             m.observe("serve_cohort_size", len(cohort))
             self._event("dispatch", jobs=len(cohort),
@@ -653,12 +662,13 @@ class FleetService:
             t.dispatch_t = now
         sched = self._scheds[idx]
         try:
-            handle2t = {
-                sched.submit(t.image, t.shared_init, threads=t.threads,
-                             tdx_dim=t.tdx_dim, tag=t.tag,
-                             weight=t.weight): t
-                for t in cohort}
-            out = self._drain(sched)
+            with obs_trace.span("serve.dispatch", jobs=len(cohort)):
+                handle2t = {
+                    sched.submit(t.image, t.shared_init,
+                                 threads=t.threads, tdx_dim=t.tdx_dim,
+                                 tag=t.tag, weight=t.weight): t
+                    for t in cohort}
+                out = self._drain(sched)
         except Exception as e:
             # the scheduler itself misbehaved (not a contained per-unit
             # failure): abandon it — its internal queue may still hold
@@ -678,11 +688,13 @@ class FleetService:
             return
         self._fail_streak[idx] = 0
         results, failures = out
-        for h, t in handle2t.items():
-            if h in results:
-                self._complete(t, results[h])
-            else:
-                self._retry_or_fail(t, "error", failures.get(h))
+        # client done-callbacks run here, on the dispatcher thread
+        with obs_trace.span("serve.resolve", jobs=len(cohort)):
+            for h, t in handle2t.items():
+                if h in results:
+                    self._complete(t, results[h])
+                else:
+                    self._retry_or_fail(t, "error", failures.get(h))
 
     def _requeue_cohort(self, cohort: list[_Ticket]) -> None:
         """Return an undispatched cohort to the shared queue untouched:

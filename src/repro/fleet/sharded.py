@@ -54,8 +54,9 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import machine as machine_mod
-from ..core.blockc import program_key
+from ..core.blockc import program_digest, program_key
 from ..core.config import EGPUConfig
+from ..core.executor import name_kernel
 from ..obs import counters as obs_counters
 from ..obs import trace as obs_trace
 from . import faults
@@ -63,7 +64,7 @@ from .devices import (balance_units, device_label, fleet_devices,
                       make_job_mesh)
 from .engine import ResidencyCache
 from .scheduler import (DrainCancelled, FleetJob, FleetScheduler,
-                        JobResult, _prog_digest, _result_checksum)
+                        JobResult, _result_checksum)
 
 __all__ = ["ShardedFleetScheduler", "mega_light_fn"]
 
@@ -76,10 +77,11 @@ def mega_light_fn(cp, mesh):
     ``(shared (N, S), tdx (N,)) -> (shared, cycles, halted)`` with the
     leading (job) axis split across the mesh's devices.  Every row is an
     independent core, so this is bit-identical to the single-device
-    call."""
-    return jax.shard_map(cp.light_fn(), mesh=mesh,
-                         in_specs=(P("jobs", None), P("jobs")),
-                         out_specs=(P("jobs", None), P("jobs"), P("jobs")))
+    call.  Its kernel is named ``egpu_mega_<tier>_<program digest>``."""
+    fn = jax.shard_map(cp.light_fn(), mesh=mesh,
+                       in_specs=(P("jobs", None), P("jobs")),
+                       out_specs=(P("jobs", None), P("jobs"), P("jobs")))
+    return name_kernel(fn, "mega_" + cp.mode, program_digest(cp.image))
 
 
 class ShardedFleetScheduler(FleetScheduler):
@@ -263,7 +265,7 @@ class ShardedFleetScheduler(FleetScheduler):
             if cp is None:               # interpreter tier: per-device
                 rest_set.update(id(j) for j in group)
                 continue
-            self._event("megabatch", program=_prog_digest(cp.image),
+            self._event("megabatch", program=program_digest(cp.image),
                         jobs=n_slabs * slab, slabs=n_slabs,
                         devices=self.n_devices, tier=cp.mode)
             for i in range(n_slabs):

@@ -27,6 +27,14 @@ module-level helpers::
     with span("dispatch", cores=n):
         ...
     event("tier_decision", tier=tier, rule=rule)
+
+While a ``jax.profiler`` session is capturing, every span is also a
+``jax.profiler.TraceAnnotation`` named ``egpu.<span name>`` (no
+arguments), so the host's stages land on the profiler's clock beside
+the device's kernels.  Capture alone is enough: with no tracer and no
+flight recorder installed, a span is then an annotation and nothing
+else (``active`` stays ``False``, so tracer-only work stays off).
+With the profiler off, the test costs one static call.
 """
 from __future__ import annotations
 
@@ -37,11 +45,20 @@ import threading
 import time
 from typing import Any
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 from . import recorder as _recorder
 
 __all__ = [
     "Tracer", "span", "event", "current_tracer", "NULL_SPAN",
+    "PROFILER_PREFIX",
 ]
+
+#: the prefix of every span's name on the profiler's timeline
+PROFILER_PREFIX = "egpu."
+
+#: ``True`` while a ``jax.profiler`` session is capturing
+_profiling = _Annotation.is_enabled
 
 _TRACER: contextvars.ContextVar["Tracer | None"] = \
     contextvars.ContextVar("repro_obs_tracer", default=None)
@@ -73,11 +90,22 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _ProfilerSpan(_Annotation):
+    """A span only the profiler sees (no tracer or recorder installed):
+    an ``egpu.<name>`` annotation whose arguments go nowhere."""
+
+    active = False
+
+    def set(self, **args):
+        return self
+
+
 class _Span:
     """One live span: records ``[enter, exit)`` as a complete event in
-    the tracer and/or the flight recorder (whichever are installed)."""
+    the tracer and/or the flight recorder (whichever are installed),
+    and annotates the profiler's timeline while it captures."""
 
-    __slots__ = ("_tr", "_rec", "_name", "_args", "_t0")
+    __slots__ = ("_tr", "_rec", "_name", "_args", "_t0", "_ann")
     active = True
 
     def __init__(self, tr: "Tracer | None", name: str, args: dict,
@@ -86,13 +114,19 @@ class _Span:
         self._rec = rec
         self._name = name
         self._args = args
+        self._ann = (_Annotation(PROFILER_PREFIX + name) if _profiling()
+                     else None)
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         tr = self._tr
         if tr is not None:
             tr._events.append({
@@ -230,17 +264,20 @@ def _jsonable(x: Any):
 
 
 def span(name: str, **args):
-    """A span against the current tracer and/or flight recorder; a
-    shared no-op when neither is installed.
+    """A span against the current tracer and/or flight recorder, and
+    the profiler while it captures; a shared no-op when none of them is
+    on.
 
-    The disabled path is two contextvar reads and ``None`` checks —
-    callers building expensive span arguments should gate on
-    ``sp.active`` (or :func:`current_tracer`) instead of precomputing.
+    The disabled path is two contextvar reads, ``None`` checks and the
+    profiler test — callers building expensive span arguments should
+    gate on ``sp.active`` (or :func:`current_tracer`) instead of
+    precomputing.
     """
     tr = _TRACER.get()
     rec = _recorder.current_recorder()
     if tr is None and rec is None:
-        return NULL_SPAN
+        return (_ProfilerSpan(PROFILER_PREFIX + name) if _profiling()
+                else NULL_SPAN)
     return _Span(tr, name, args, rec=rec)
 
 
