@@ -153,14 +153,14 @@ def _wfs_table(cfg: EGPUConfig, threads: int) -> list[int]:
 
 def _tsc_static(cfg: EGPUConfig, tsc: int, threads: int):
     """(wfs, tsc_mask) for one instruction — everything Table 3 encodes,
-    folded to Python/NumPy constants."""
+    folded to Python/NumPy constants.  The mask spans the program's own
+    ``threads``, the thread axis the compiled kernels trace."""
     width_code = (tsc >> 2) & 3
     depth_code = tsc & 3
     wfs = _wfs_table(cfg, threads)[depth_code]
     lanes = isa.WIDTH_LANES[width_code]
-    tid = np.arange(cfg.max_threads)
-    tsc_mask = ((tid % cfg.num_sps < lanes) & (tid // cfg.num_sps < wfs)
-                & (tid < threads))
+    tid = np.arange(threads)
+    tsc_mask = (tid % cfg.num_sps < lanes) & (tid // cfg.num_sps < wfs)
     return wfs, tsc_mask
 
 
@@ -787,6 +787,12 @@ class CompiledProgram:
     ``self.switch_dispatches`` counts the block-driver ``lax.switch``
     dispatches the program pays on this tier (0 on the superblock tier —
     that is the point).
+
+    The kernels trace the thread axis at the program's runtime thread
+    count (``self.kernel_threads``), not ``cfg.max_threads``: threads
+    past it never act, so they are not traced.  The full runners pad
+    registers and predicate state back to ``max_threads`` with the
+    zeros the interpreter leaves there.
     """
 
     def __init__(self, image: ProgramImage, threads: int, *,
@@ -821,7 +827,9 @@ class CompiledProgram:
             p2b[s:e] = bi
         self._pc2block = p2b
         self._tables = tables_np(cfg)
-        self._tid = np.arange(cfg.max_threads, dtype=np.int32)
+        #: the thread axis the kernels trace (see the class docstring)
+        self.kernel_threads = threads
+        self._tid = np.arange(threads, dtype=np.int32)
         self._tid0 = self._tid == 0
         self.schedule = self.sim.schedule
         self.policy = DEFAULT_TIER_POLICY if policy is None else policy
@@ -1086,7 +1094,7 @@ class CompiledProgram:
         """Traced: fresh state -> final dynamic leaves, per the folded
         static path."""
         cfg = self.cfg
-        T, R = cfg.max_threads, cfg.regs_per_thread
+        T, R = self.kernel_threads, cfg.regs_per_thread
         D = max(1, cfg.predicate_levels)
         batch = shared.shape[:-1]              # () or (B,)
         return self._apply_schedule(self.schedule, (
@@ -1121,9 +1129,10 @@ class CompiledProgram:
                 x = jnp.asarray(x)
                 return jnp.broadcast_to(x, batch + x.shape)
 
+            pad = self._pad_threads
             return MachineState(
-                regs=regs, shared=shared_f, pstack=pstack,
-                pdepth=b(pdepth), lctr=b(jnp.asarray(sim.lctr)),
+                regs=pad(regs, -2), shared=shared_f, pstack=pad(pstack, -2),
+                pdepth=b(pad(pdepth, -1)), lctr=b(jnp.asarray(sim.lctr)),
                 lsp=b(jnp.int32(sim.lsp)),
                 cstack=b(jnp.asarray(sim.cstack)),
                 csp=b(jnp.int32(sim.csp)), pc=b(jnp.int32(sim.pc)),
@@ -1138,6 +1147,18 @@ class CompiledProgram:
 
         return jax.jit(self._named(run, "superblock"), donate_argnums=(0,))
 
+    def _pad_threads(self, x, axis: int):
+        """``x``'s thread ``axis`` zero-padded from the kernel's width to
+        ``cfg.max_threads``: what the interpreter leaves in threads at or
+        past the runtime count, whose every register, predicate and
+        store write is masked off (DOT/SUM write only thread 0)."""
+        extra = self.cfg.max_threads - x.shape[axis]
+        if not extra:
+            return x
+        widths = [(0, 0)] * x.ndim
+        widths[axis] = (0, extra)
+        return jnp.pad(x, widths)
+
     def _named(self, fn, tier: str):
         """``fn`` named as this program's ``tier`` kernel."""
         return name_kernel(fn, tier, program_digest(self.image))
@@ -1151,7 +1172,7 @@ class CompiledProgram:
         fns.append(self._pad_stop_fn())
         pc2block = jnp.asarray(self._pc2block)
         cfg = self.cfg
-        T, R = cfg.max_threads, cfg.regs_per_thread
+        T, R = self.kernel_threads, cfg.regs_per_thread
         D = max(1, cfg.predicate_levels)
         max_steps = cfg.max_steps
         prog_len = self.prog_len
@@ -1197,9 +1218,11 @@ class CompiledProgram:
                 x = jnp.asarray(x)
                 return jnp.broadcast_to(x, batch + x.shape)
 
+            pad = self._pad_threads
             return MachineState(
-                regs=d.regs, shared=d.shared, pstack=d.pstack,
-                pdepth=b(s.pdepth), lctr=b(s.lctr), lsp=b(s.lsp),
+                regs=pad(d.regs, -2), shared=d.shared,
+                pstack=pad(d.pstack, -2),
+                pdepth=b(pad(s.pdepth, -1)), lctr=b(s.lctr), lsp=b(s.lsp),
                 cstack=b(s.cstack), csp=b(s.csp), pc=b(s.pc),
                 cycles=b(s.cycles), steps=b(s.steps), halted=b(s.halted),
                 threads_active=b(jnp.int32(threads)),

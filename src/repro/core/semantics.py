@@ -130,7 +130,7 @@ def _sel(c, a, b):
     return jnp.where(c, a, b)
 
 
-def det_sum(v, num_sps: int = 16):
+def det_sum(v, num_sps: int = 16, width: int | None = None):
     """Deterministic thread-space reduction (DOT/SUM extension unit).
 
     Sequential over wavefronts, pairwise tree within the 16-lane
@@ -138,12 +138,31 @@ def det_sum(v, num_sps: int = 16):
     block compiler and the vmapped fleet produce bit-identical sums
     (``jnp.sum`` may associate differently under vmap/batching).  ``v``
     is ``(..., T)``; returns ``(...)``.
+
+    ``width`` is the thread space the sum stands for when ``v`` holds
+    only its first ``T`` threads (the compiled tiers trace the program's
+    own thread count).  Each wavefront past ``T`` is masked off and
+    would add ``+0.0``, which turns a ``-0.0`` sum into ``+0.0`` and
+    flushes a denormal where the device does; since ``(x + 0) + 0 ==
+    x + 0`` for every float, one add of ``+0.0`` reproduces them all.
+    The zero passes an optimization barrier, or XLA folds ``x + 0.0``
+    away.  The same zero is added to every term first: that changes
+    only the sign of a zero term (and flushes a denormal one), which the
+    last add erases, and it keeps the CPU backend from contracting a
+    DOT product into the first add as an FMA (one rounding where the
+    interpreter, whose mask is traced, rounds twice).
     """
     T = v.shape[-1]
+    zero = None
+    if width is not None and T < width:
+        zero = lax.optimization_barrier(jnp.zeros((), v.dtype))
+        v = v + zero
     m = v.reshape(v.shape[:-1] + (T // num_sps, num_sps))
     acc = m[..., 0, :]
     for i in range(1, T // num_sps):
         acc = acc + m[..., i, :]
+    if zero is not None:
+        acc = acc + zero
     s = num_sps // 2
     while s >= 1:
         acc = acc[..., :s] + acc[..., s:2 * s]
@@ -328,11 +347,12 @@ def build_spec(env: OpEnv) -> list:
     # extension units: DOT/SUM land in thread 0's Rd.
     def f_dot():
         s = det_sum(jnp.where(env.mask, _f(rav) * _f(rbv), 0.0),
-                    cfg.num_sps)
+                    cfg.num_sps, cfg.max_threads)
         return jnp.broadcast_to(_bits(s)[..., None], rav.shape)
 
     def f_sum():
-        s = det_sum(jnp.where(env.mask, _f(rav), 0.0), cfg.num_sps)
+        s = det_sum(jnp.where(env.mask, _f(rav), 0.0), cfg.num_sps,
+                    cfg.max_threads)
         return jnp.broadcast_to(_bits(s)[..., None], rav.shape)
 
     def f_invsqr(): return _bits(lax.rsqrt(_f(rav)))

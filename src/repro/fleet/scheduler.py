@@ -404,6 +404,13 @@ def register_fleet_metrics(reg: obs_metrics.MetricsRegistry) -> None:
                 ("from_tier", "to_tier"))
     reg.counter("fleet_bisections_total",
                 "failing batches bisected by the isolated drain")
+    reg.counter("fleet_lane_steps_offered_total",
+                "lane-steps the real jobs offered: vector retires x the "
+                "program's runtime threads", ("tier",))
+    reg.counter("fleet_lane_steps_traced_total",
+                "lane-steps the kernels traced: vector retires x the "
+                "kernel's thread axis, over every slot of the batch",
+                ("tier",))
     reg.histogram("fleet_dispatch_seconds",
                   "XLA dispatch wall per compiled-tier batch",
                   ("tier", "device"))
@@ -745,6 +752,16 @@ class FleetScheduler:
         m.inc("fleet_wall_seconds_total", wall)
         m.inc("fleet_cycles_total", sum_cycles)
         m.inc("fleet_steps_total", sum_steps)
+        if self.validate:         # the mix is collected only then
+            # vector retires per slot: every step but the scalar
+            # (sequencer and NOP) classes
+            vec = steps - stat_i[:, int(isa.OpClass.NOPC)] \
+                - stat_i[:, int(isa.OpClass.BRANCH)]
+            threads = np.asarray([j.threads for j in batch[:real]])
+            m.inc("fleet_lane_steps_offered_total",
+                  int((vec[:real] * threads).sum()), tier="interp")
+            m.inc("fleet_lane_steps_traced_total",
+                  int(vec.sum()) * self.cfg.max_threads, tier="interp")
         m.inc("fleet_collect_seconds_total", time.perf_counter() - t0)
 
     def _job_counters(self, job: FleetJob) -> EventCounters | None:
@@ -861,6 +878,11 @@ class FleetScheduler:
         m.inc("fleet_wall_seconds_total", wall)
         m.inc("fleet_cycles_total", cycles * real)
         m.inc("fleet_steps_total", steps * real)
+        offered = counters.lane_steps_offered
+        m.inc("fleet_lane_steps_offered_total", offered * real, tier=cp.mode)
+        m.inc("fleet_lane_steps_traced_total",
+              offered // cp.threads * cp.kernel_threads * len(batch),
+              tier=cp.mode)
         m.inc("fleet_collect_seconds_total", time.perf_counter() - t0)
 
     def _run_compiled_unit(self, cp, chunk: list[FleetJob],
@@ -885,8 +907,8 @@ class FleetScheduler:
             self._m.inc("fleet_compile_cache_total",
                         result="miss" if compile_s else "hit")
             t_disp = time.perf_counter()
-            with obs_trace.span("dispatch", cores=size,
-                                device=self._dev):
+            with obs_trace.span("dispatch", cores=size, device=self._dev,
+                                kernel_threads=cp.kernel_threads):
                 faults.maybe_raise("dispatch", tier=cp.mode, cores=size,
                                    device=self._dev)
                 shared_out, _, _ = cp.run_light_dev(shared_dev, tdx_dev,

@@ -221,7 +221,8 @@ class ShardedFleetScheduler(FleetScheduler):
             exe, compile_s = self._mega_exec(cp, shared_dev, tdx_dev)
             self._m.inc("fleet_compile_seconds_total", compile_s)
             t_disp = time.perf_counter()
-            with obs_trace.span("dispatch", cores=real, device="mesh"):
+            with obs_trace.span("dispatch", cores=real, device="mesh",
+                                kernel_threads=cp.kernel_threads):
                 faults.maybe_raise("dispatch", tier=cp.mode, cores=real,
                                    device="mesh")
                 shared_out, _, _ = exe(shared_dev, tdx_dev)

@@ -8,6 +8,7 @@ instance, 32 cores per batch — without chip time.  The topology is
 described inside a fixture, never while a module is imported, so every
 test worker collects the same tests.
 """
+import functools
 import os
 
 import jax
@@ -66,6 +67,9 @@ def _spec(shape, dtype, sharding):
 @pytest.mark.parametrize("build,n,tier", [
     (build_reduction, 32, "superblock"),      # short, straight-line
     (build_matmul, 64, "superblock"),         # loop-heavy: fori repeats
+    # the drains' hot kernel, traced on its own 64 of 512 threads
+    pytest.param(functools.partial(build_matmul, use_dot=True), 64,
+                 "superblock", id="build_matmul_dot-64-superblock"),
 ])
 def test_superblock_light_path_compiles_for_one_chip(
         no_persistent_cache, one_chip, build, n, tier):
